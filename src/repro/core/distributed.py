@@ -17,7 +17,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from .kernels_math import KernelParams
 from .packing import PackedBlocks, PackedPrediction
@@ -67,7 +66,7 @@ def distributed_loglik(
         ll = batched_block_loglik(p, bx, by, bm, nx, ny, nm, nu=nu)
         return jax.lax.psum(ll, axis)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(), spec, spec, spec, spec, spec, spec),
         out_specs=P(),
@@ -108,12 +107,12 @@ def _predict_shard_fn(mesh: Mesh, axis: str, nu: float, backend: str):
     def local(p, qx, qm, nx, ny, nm):
         return batched_block_predict(p, qx, qm, nx, ny, nm, nu=nu, backend=backend)
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(),) + (spec,) * 5,
         out_specs=(spec, spec),
-        # pallas_call has no replication rule; outputs are per-shard anyway
-        check_rep=False,
+        # pallas_call has no varying-axes rule; outputs are per-shard anyway
+        check_vma=False,
     ))
 
 
@@ -223,7 +222,7 @@ def distributed_neg_loglik_fn(packed, nu, mesh, axis="workers"):
     local = lambda p, bx, by, bm, nx, ny, nm: jax.lax.psum(
         batched_block_loglik(p, bx, by, bm, nx, ny, nm, nu=nu), axis
     )
-    fn = shard_map(local, mesh=mesh, in_specs=(P(),) + (spec,) * 6, out_specs=P())
+    fn = jax.shard_map(local, mesh=mesh, in_specs=(P(),) + (spec,) * 6, out_specs=P())
 
     def loss(params):
         return -fn(params, *arrs) / n
@@ -249,7 +248,7 @@ def _bucketed_neg_loglik_fn(bucketed, nu, mesh, axis, n_workers):
         local = lambda p, bx, by, bm, nx, ny, nm: jax.lax.psum(
             batched_block_loglik(p, bx, by, bm, nx, ny, nm, nu=nu), axis
         )
-        fn = shard_map(local, mesh=mesh, in_specs=(P(),) + (spec,) * 6,
+        fn = jax.shard_map(local, mesh=mesh, in_specs=(P(),) + (spec,) * 6,
                        out_specs=P())
         per_bucket.append((fn, arrs))
 

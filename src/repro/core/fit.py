@@ -116,7 +116,6 @@ def _chunk_grad_fn(nu: float, backend: str, n_points: int, mesh=None,
 
         return jax.jit(jax.value_and_grad(f))
 
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     spec = P(axis)
@@ -124,11 +123,11 @@ def _chunk_grad_fn(nu: float, backend: str, n_points: int, mesh=None,
     def local(params, *arrs):
         return jax.lax.psum(ll(params, *arrs), axis)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh, in_specs=(P(),) + (spec,) * 6, out_specs=P(),
-        # pallas_call has no replication rule (same caveat as the
+        # pallas_call has no varying-axes rule (same caveat as the
         # prediction shard_map); the psum output is replicated anyway
-        check_rep=backend == "ref",
+        check_vma=backend == "ref",
     )
 
     def f(params, *arrs):
@@ -493,9 +492,11 @@ def _fit_sbv_streaming(
              "device_cached_pieces": 0, "device_cached_bytes": 0,
              "h2d_bytes_per_step": 0, "inner_steps_total": 0,
              "inner_time_s": 0.0, "precision": tier or "f64",
-             "device_cache_budget": 0}
+             "device_cache_budget": 0, "struct_time_s": 0.0,
+             "step_times_s": [], "backends": []}
 
     for outer in range(outer_rounds):
+        t_struct = time.perf_counter()
         beta_np = np.asarray(params.beta)
         struct = streaming_preprocess(store, beta_np, cfg, stream_chunk)
         bc_pad = max(len(r) for r in struct.plan)
@@ -542,6 +543,7 @@ def _fit_sbv_streaming(
         work_dir = spool_dir or tempfile.mkdtemp(prefix="sbv-spool-")
         spool = PackedChunkSpool(os.path.join(work_dir, f"round{outer}"),
                                  device_budget=budget, sharding=sharding)
+        backends = set()
         try:
             for ranks in struct.plan:
                 packed = pack_block_chunk(
@@ -570,9 +572,14 @@ def _fit_sbv_streaming(
                         # owner-contiguous reorder; bc already divides the
                         # shard count, so the shape is unchanged
                         p = shard_blocks_by_owner(p, n_shards)
-                    spool.add(p, tag=_piece_backend(backend, p))
+                    piece_backend = _piece_backend(backend, p)
+                    backends.add(piece_backend)
+                    spool.add(p, tag=piece_backend)
+            stats["struct_time_s"] += time.perf_counter() - t_struct
+            stats["backends"] = sorted(backends)
             stats.update(
                 n_chunks=len(struct.plan), n_pieces=len(spool),
+                piece_blocks=bc_pad,  # uniform-layout piece block count
                 packed_chunk_bytes_max=max(stats["packed_chunk_bytes_max"],
                                            spool.packed_bytes_max),
                 spool_bytes=max(stats["spool_bytes"], spool.packed_bytes_total),
@@ -586,9 +593,16 @@ def _fit_sbv_streaming(
                                         spool.device_bytes),
             )
 
+            if mesh is not None:
+                # Replicate the params over the mesh up front: the sharded
+                # step returns a replicated gradient, so after the first
+                # update they would carry that placement and the chunk
+                # step would compile a second time.
+                params = jax.device_put(params, NamedSharding(mesh, P()))
             state = adam_init(params)
             t_inner = time.perf_counter()
             for it in range(inner_steps):
+                t_step = time.perf_counter()
                 loss = None
                 grad = None
                 for arrs, piece_backend in spool.iter_arrays(prefetch=prefetch):
@@ -597,7 +611,8 @@ def _fit_sbv_streaming(
                     loss = v if loss is None else loss + v
                     grad = g if grad is None else jax.tree.map(jnp.add, grad, g)
                 params, state = adam_update(grad, state, params, lr)
-                history.append((outer, it, float(loss)))
+                history.append((outer, it, float(loss)))  # float() syncs
+                stats["step_times_s"].append(time.perf_counter() - t_step)
                 if verbose and it % 10 == 0:
                     print(f"[fit-stream] outer={outer} it={it} "
                           f"nll/n={float(loss):.6f} pieces={len(spool)} "

@@ -22,7 +22,8 @@ predict program (shapes are rounded up so the jit cache is reused).
 """
 from __future__ import annotations
 
-from dataclasses import dataclass
+import time
+from dataclasses import dataclass, field
 from functools import partial
 
 import jax
@@ -47,6 +48,12 @@ class Prediction:
     sim_mean: np.ndarray   # conditional-simulation sample mean
     ci_low: np.ndarray     # 95% CI bounds from simulation
     ci_high: np.ndarray
+    # What the call ran: ``backends`` (the concrete predict programs),
+    # ``shapes`` ((bc, bs_pred, m_pred) per piece), ``host_s`` (training
+    # index + query NNS/packing + casts) and ``device_s`` (per chunk:
+    # dispatch, device run, fetch and scatter; a first compile lands in
+    # the first chunk).
+    stats: dict = field(default_factory=dict)
 
 
 @dataclass
@@ -255,6 +262,23 @@ def _predict_one(params, nu, qx, qmask, nx, ny, nmask):
     return mu, jnp.maximum(var, 1e-12)
 
 
+def resolve_backend(backend: str, params, q_x, nn_x) -> str:
+    """The concrete program ``batched_block_predict`` runs for ``backend``
+    on these operands: multi-output params always take the vmapped
+    program, and ``'auto'`` resolves per block shape and coordinate dtype
+    (``kernels.ops.select_backend``)."""
+    from .multioutput import MultiOutputParams
+
+    if isinstance(params, MultiOutputParams):
+        return "ref"
+    if backend == "auto":
+        from repro.kernels import ops as kops
+
+        return kops.select_backend(q_x.shape[1], nn_x.shape[1],
+                                   kind="predict", dtype=q_x.dtype)
+    return backend
+
+
 @partial(jax.jit, static_argnames=("nu", "backend"))
 def batched_block_predict(
     params: KernelParams,
@@ -283,12 +307,7 @@ def batched_block_predict(
         return jax.vmap(
             lambda a, b, c, d, e: _predict_multi_one(params, nu, a, b, c, d, e)
         )(q_x, q_mask, nn_x, nn_y, nn_mask)
-    if backend == "auto":
-        from repro.kernels import ops as kops
-
-        backend = kops.select_backend(
-            q_x.shape[1], nn_x.shape[1], kind="predict", dtype=q_x.dtype
-        )
+    backend = resolve_backend(backend, params, q_x, nn_x)
     if backend == "ref":
         return jax.vmap(
             lambda a, b, c, d, e: _predict_one(params, nu, a, b, c, d, e)
@@ -481,6 +500,7 @@ def predict_sbv(
     elif isinstance(params, MultiOutputParams):
         params = params.output_params(0)
 
+    t_mark = time.perf_counter()
     beta = np.asarray(params.beta if beta_struct is None else beta_struct)
     if is_store(x_test):
         n_test = x_test.n_rows
@@ -498,6 +518,8 @@ def predict_sbv(
     sim_mean = np.zeros(out_shape)
     sim_std = np.zeros(out_shape)
     key = jax.random.PRNGKey(seed)
+    stats = {"backends": set(), "shapes": set(), "host_s": 0.0,
+             "device_s": []}
 
     for ci, packed in iter_query_chunks(
         index, x_test, bs_pred, m_pred, alpha=alpha, seed=seed,
@@ -517,14 +539,20 @@ def predict_sbv(
 
             pieces = [cast_prediction(p, tier) for p in pieces]
         key_c = jax.random.fold_in(key, ci)
+        t_dev = time.perf_counter()
+        stats["host_s"] += t_dev - t_mark
         for bi, piece in enumerate(pieces):
             # Uniform path keeps the pre-bucketing key stream (bit-stable
             # sim draws); buckets get independent per-bucket streams.
             key_b = key_c if not n_buckets else jax.random.fold_in(key_c, bi)
+            piece_backend = resolve_backend(backend, params, piece.q_x,
+                                            piece.nn_x)
+            stats["backends"].add(piece_backend)
+            stats["shapes"].add((piece.n_blocks, piece.bs_pred, piece.m_pred))
             if multihost is None:
                 mu_b, var_b, sm_b, ss_b = _predict_and_simulate(
                     params, *(jnp.asarray(a) for a in piece.arrays()),
-                    key_b, nu=nu, backend=backend, n_sims=n_sims,
+                    key_b, nu=nu, backend=piece_backend, n_sims=n_sims,
                 )
                 scatter_packed(piece, (mu_b, mean), (var_b, var),
                                (sm_b, sim_mean), (ss_b, sim_std))
@@ -540,11 +568,13 @@ def predict_sbv(
                 sub = _slice_prediction_blocks(piece, lo, hi)
                 mu_b, var_b, sm_b, ss_b = _predict_and_simulate_span(
                     params, *(jnp.asarray(a) for a in sub.arrays()),
-                    key_b, nu=nu, backend=backend, n_sims=n_sims,
+                    key_b, nu=nu, backend=piece_backend, n_sims=n_sims,
                     lo=lo, bc_full=bc_full,
                 )
                 scatter_packed(sub, (mu_b, mean), (var_b, var),
                                (sm_b, sim_mean), (ss_b, sim_std))
+        t_mark = time.perf_counter()
+        stats["device_s"].append(t_mark - t_dev)
 
     if multihost is not None:
         # Ranks filled disjoint result rows (block spans own disjoint
@@ -558,9 +588,12 @@ def predict_sbv(
         mean, var, sim_mean, sim_std = (
             a[:, None] for a in (mean, var, sim_mean, sim_std))
     z975 = 1.959963984540054
+    stats.update(backends=sorted(stats["backends"]),
+                 shapes=sorted(stats["shapes"]))
     return Prediction(
         mean=mean, var=var, sim_mean=sim_mean,
         ci_low=sim_mean - z975 * sim_std, ci_high=sim_mean + z975 * sim_std,
+        stats=stats,
     )
 
 

@@ -326,13 +326,16 @@ class LazyFlatBlocks(_FlatBlocks):
                 self._cache[b] = pts[off:off + k]
                 self._cache_bytes += self._cache[b].nbytes
                 off += k
-            self._evict()
         out = []
         for b in block_ids:
             b = int(b)
             pts = self._cache[b]
             self._cache.move_to_end(b)
             out.append(pts)
+        # Evict only after the result is assembled: one call's candidate
+        # set may outgrow the cap, and evicting first would drop blocks
+        # this call still has to return.
+        self._evict()
         return out[0] if len(out) == 1 else np.concatenate(out)
 
 
@@ -465,21 +468,29 @@ def device_cache_budget(frac: float = 0.5, reserve_bytes: int = 0) -> int:
     ``reserve_bytes``, the headroom the caller needs for compute (the
     streaming fit passes its ``working_set_model`` device-grad term so
     the cache can never squeeze out the backward pass's live set). On the
-    CPU backend, device memory IS host RAM, so MemAvailable stands in;
-    when neither source is readable, a conservative 4GB is assumed.
+    CPU backend, device memory IS host RAM, so MemAvailable stands in
+    (a conservative 4GB when that is unreadable too). On an accelerator
+    a missing ``memory_stats`` is an error: host RAM says nothing about
+    HBM, and a guessed budget could overfill the device.
     """
     import jax
 
-    free = None
     dev = jax.devices()[0]
+    on_cpu = dev.platform == "cpu"
     try:
         stats = dev.memory_stats()
     except Exception:
+        if not on_cpu:
+            raise
         stats = None
     if stats and stats.get("bytes_limit"):
         free = int(stats["bytes_limit"]) - int(stats.get("bytes_in_use", 0))
-    if free is None:
+    elif on_cpu:
         free = _host_available_bytes() or (4 << 30)
+    else:
+        raise RuntimeError(
+            f"{dev.platform} device reports no memory_stats bytes_limit; "
+            "pass device_cache= (bytes) to size the device cache")
     return max(0, int(frac * free) - int(reserve_bytes))
 
 

@@ -13,7 +13,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from repro.core.kernels_math import KernelParams
+from repro.core.kernels_math import KernelParams, cast_params
 from repro.core.vecchia import batched_block_loglik
 
 from .matern_cov import matern_cov_pallas
@@ -66,13 +66,24 @@ def _fwd(params, blk_x, blk_y, blk_mask, nn_x, nn_y, nn_mask, nu):
 
 def _bwd(nu, res, g):
     params, blk_x, blk_y, blk_mask, nn_x, nn_y, nn_mask = res
+    # The reference VJP runs at the forward's accumulation width: f64
+    # master params would otherwise promote it to f64, which a TPU only
+    # emulates (slowly, at twice the memory). The cast is differentiable,
+    # so the gradient still arrives in the params' own dtype.
+    _, acc = ladder_dtypes(blk_x.dtype)
     grad_fn = jax.grad(
         lambda p, by, ny: _ref_total(
-            p, blk_x, by, blk_mask.astype(bool), nn_x, ny, nn_mask.astype(bool), nu
+            cast_params(p, acc), blk_x, by, blk_mask.astype(bool), nn_x, ny,
+            nn_mask.astype(bool), nu
         ),
         argnums=(0, 1, 2),
     )
-    gp, gby, gny = grad_fn(params, blk_y, nn_y)
+    # f32 matmuls default to one bf16 pass on TPU, and XLA's Cholesky and
+    # triangular solves are built from them: at that precision the
+    # near-nugget Schur complements factor to NaN. Full f32 keeps them
+    # finite (a no-op on the CPU).
+    with jax.default_matmul_precision("highest"):
+        gp, gby, gny = grad_fn(params, blk_y, nn_y)
     scale = lambda t: jax.tree.map(lambda a: a * g, t)
     zeros_like = lambda a: jnp.zeros_like(a)
     return (
@@ -116,15 +127,17 @@ def _ms_bwd(nu, res, g):
 
     params0, blk_x, blk_y, blk_mask, nn_x, nn_y, nn_mask = res
     g_ld, g_q = g
+    _, acc = ladder_dtypes(blk_x.dtype)  # same width contract as _bwd
 
     def combo(p, by, ny):
         ld, q = batched_multi_stats(
-            p, blk_x, by, blk_mask.astype(bool), nn_x, ny,
+            cast_params(p, acc), blk_x, by, blk_mask.astype(bool), nn_x, ny,
             nn_mask.astype(bool), nu=nu,
         )
         return g_ld * ld + jnp.sum(g_q * q)
 
-    gp, gby, gny = jax.grad(combo, argnums=(0, 1, 2))(params0, blk_y, nn_y)
+    with jax.default_matmul_precision("highest"):  # see _bwd
+        gp, gby, gny = jax.grad(combo, argnums=(0, 1, 2))(params0, blk_y, nn_y)
     zeros_like = lambda a: jnp.zeros_like(a)
     return (
         gp, zeros_like(blk_x), gby, zeros_like(blk_mask),
@@ -148,15 +161,19 @@ def select_backend(bs: int, m: int, kind: str = "predict", dtype=None) -> str:
     Dtype policy (the full matrix is pinned in tests/test_buckets.py):
     the compiled tiled path takes f32 buckets aligned to the native
     (8, 128) tile and bf16-assembly buckets aligned to bf16's doubled
-    (16, 128) sublane tile; f64 — which the compiled TPU kernel refuses —
-    and unaligned/narrow shapes fall through to the fused ``pallas``
-    kernel or the vmapped ``ref`` program by size.
+    (16, 128) sublane tile; unaligned/narrow shapes fall through to the
+    fused ``pallas`` kernel or the vmapped ``ref`` program by size. f64
+    reaches a Pallas kernel only on the CPU, where kernels run in
+    interpret mode: the compiled TPU kernels take f32/bf16 alone, so on
+    an accelerator f64 (and an unknown dtype) resolves to ``ref``.
     """
     import numpy as _np
 
     dt = None if dtype is None else _np.dtype(dtype)
     bf16 = dt is not None and dt == _np.dtype(jnp.bfloat16)
     tiled_ok = bf16 or (dt is not None and dt == _np.float32)
+    if not tiled_ok and jax.default_backend() != "cpu":
+        return "ref"
     sublane = 16 if bf16 else 8
     if kind == "predict" and tiled_ok and bs % sublane == 0 and m % 128 == 0:
         return "pallas_tiled"

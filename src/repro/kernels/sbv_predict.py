@@ -28,7 +28,10 @@ import jax.numpy as jnp
 
 from repro.core.packing import tile_predict_shapes
 
-from .sbv_loglik import _cholesky_inplace, _forward_sub, _masked_cov_tile
+from .sbv_loglik import (
+    _block_factor, _block_spec, _check_compiled_dtypes, _forward_sub,
+    _param_operands, _rows,
+)
 
 
 def _sbv_predict_kernel(
@@ -37,44 +40,20 @@ def _sbv_predict_kernel(
     mu_ref, var_ref,
     *, nu: float, narrow_gemm: bool = False,
 ):
-    beta = beta_ref[...]              # (d,) accumulation dtype
-    sigma2 = scal_ref[0]
-    nugget = scal_ref[1]
-    acc = beta.dtype                  # ladder accumulation dtype
-
-    # Same assembly/accumulation split as the likelihood kernel: coords
-    # scale at their own storage width, the GEMM accumulates in ``acc``.
-    xq = q_x_ref[0]
-    xn = nn_x_ref[0]
-    zq = xq / beta.astype(xq.dtype)   # (bs, d) scaled query coords
-    zn = xn / beta.astype(xn.dtype)   # (m, d) scaled neighbor coords
-    mq = q_m_ref[0]                   # (bs,) float mask, acc dtype
-    mn = nn_m_ref[0]                  # (m,)
-    yn = nn_y_ref[0] * mn
-
-    k_con = _masked_cov_tile(zn, zn, mn, mn, sigma2, nugget, nu, identity=True,
-                             acc=acc, narrow_gemm=narrow_gemm)
-    k_cross = _masked_cov_tile(zn, zq, mn, mq, sigma2, nugget, nu,
-                               identity=False, acc=acc, narrow_gemm=narrow_gemm)
-
-    # Same tier-aware pivot clamp as the likelihood kernel: bf16 assembly
-    # error can nudge k_con off positive-definite near the nugget scale.
-    if xq.dtype == acc:
-        floor = 1e-30
-    else:
-        floor = jnp.finfo(xq.dtype).eps * sigma2
-
-    l_con = _cholesky_inplace(k_con, floor=floor)
+    # Same assembly/accumulation split and tier-aware pivot clamp as the
+    # likelihood kernel (_block_factor).
+    f = _block_factor(beta_ref, scal_ref, q_x_ref, q_m_ref, nn_x_ref,
+                      nn_m_ref, nu, narrow_gemm)
+    bs = f["k_cross"].shape[1]
+    yn = (nn_y_ref[0] * f["mn"]).T    # (m, 1)
     # Joint solve against [K_cross | y_nn]: one substitution pass.
-    rhs = jnp.concatenate([k_cross, yn[:, None]], axis=1)   # (m, bs+1)
-    sol = _forward_sub(l_con, rhs)
-    a = sol[:, :-1]                   # (m, bs)
-    z = sol[:, -1]                    # (m,)
+    sol = _forward_sub(f["l_con"], jnp.concatenate([f["k_cross"], yn], axis=1))
+    a = sol[:, :bs]                   # (m, bs)
+    z = sol[:, bs:]                   # (m, 1)
 
-    mu = jnp.dot(a.T, z, preferred_element_type=a.dtype)
-    prior = sigma2 + nugget
-    var = prior - jnp.sum(a * a, axis=0)
-    mu_ref[0] = mu * mq
+    mu = jnp.sum(a * z, axis=0, keepdims=True)                  # (1, bs)
+    var = (f["sigma2"] + f["nugget"]) - jnp.sum(a * a, axis=0, keepdims=True)
+    mu_ref[0] = mu * f["mb"]
     var_ref[0] = jnp.maximum(var, 1e-12)
 
 
@@ -97,36 +76,28 @@ def sbv_predict_pallas(
     bc, bs, d = q_x.shape
     m = nn_x.shape[1]
     dtype = nn_y.dtype  # accumulation dtype; q_x/nn_x may be narrower
-    scal = jnp.stack([jnp.asarray(sigma2, dtype), jnp.asarray(nugget, dtype)])
-    beta = jnp.asarray(beta, dtype)
-
-    grid = (bc,)
+    _check_compiled_dtypes(interpret, q_x, nn_x, nn_y)
+    params, param_specs = _param_operands(beta, sigma2, nugget, dtype)
     # Narrow MXU GEMM operands on hardware, f32-upcast in interpret mode
     # (faithful MXU accumulation emulation — see _masked_cov_tile).
     kernel = functools.partial(_sbv_predict_kernel, nu=nu,
                                narrow_gemm=not interpret)
-    return pl.pallas_call(
+    row = jax.ShapeDtypeStruct((bc, 1, bs), dtype)
+    mu, var = pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((d,), lambda i: (0,)),            # beta (replicated)
-            pl.BlockSpec((2,), lambda i: (0,)),            # sigma2, nugget
-            pl.BlockSpec((1, bs, d), lambda i: (i, 0, 0)),
-            pl.BlockSpec((1, bs), lambda i: (i, 0)),
-            pl.BlockSpec((1, m, d), lambda i: (i, 0, 0)),
-            pl.BlockSpec((1, m), lambda i: (i, 0)),
-            pl.BlockSpec((1, m), lambda i: (i, 0)),
+        grid=(bc,),
+        in_specs=param_specs + [
+            _block_spec(bs, d),
+            _block_spec(1, bs),
+            _block_spec(m, d),
+            _block_spec(1, m),
+            _block_spec(1, m),
         ],
-        out_specs=(
-            pl.BlockSpec((1, bs), lambda i: (i, 0)),
-            pl.BlockSpec((1, bs), lambda i: (i, 0)),
-        ),
-        out_shape=(
-            jax.ShapeDtypeStruct((bc, bs), dtype),
-            jax.ShapeDtypeStruct((bc, bs), dtype),
-        ),
+        out_specs=(_block_spec(1, bs), _block_spec(1, bs)),
+        out_shape=(row, row),
         interpret=interpret,
-    )(beta, scal, q_x, q_mask, nn_x, nn_y, nn_mask)
+    )(*params, q_x, _rows(q_mask), nn_x, _rows(nn_y), _rows(nn_mask))
+    return mu[:, 0, :], var[:, 0, :]
 
 
 @functools.partial(jax.jit, static_argnames=("nu", "interpret"))
@@ -153,13 +124,6 @@ def sbv_predict_tiled(
     16-sublane tile — see docs/precision.md); interpret mode (CPU)
     accepts f64 as well.
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    if not interpret and q_x.dtype not in (jnp.float32, jnp.bfloat16):
-        raise TypeError(
-            "compiled TPU predict kernel needs float32 or bfloat16 assembly "
-            f"inputs, got {q_x.dtype}"
-        )
     bc, bs, _ = q_x.shape
     m = nn_x.shape[1]
     # bf16 min tile is (16, 128): the sublane side doubles vs f32's (8, 128).

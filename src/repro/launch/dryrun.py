@@ -28,7 +28,6 @@ from repro.analysis.hlo_analysis import analyze_compiled, model_flops, roofline
 from repro.configs import ARCHS, SHAPES, applicable, get_config
 from repro.launch.mesh import make_production_mesh
 from repro.launch.specs import SBV_GP_SHAPES, build_cell
-from repro.sharding.compat import set_mesh
 
 MESHES = {"pod": False, "multipod": True}
 
@@ -42,7 +41,7 @@ def run_cell(arch: str, shape_name: str, mesh_name: str, verbose: bool = True) -
         step, in_shardings=in_sh, out_shardings=out_sh,
         donate_argnums=donate or None,
     )
-    with set_mesh(mesh):  # activates activation-sharding constraints
+    with jax.set_mesh(mesh):  # activates activation-sharding constraints
         lowered = jitted.lower(*args)
         t_lower = time.time() - t0
         t0 = time.time()
@@ -101,6 +100,9 @@ def main(argv=None):
     ap.add_argument("--resume", action="store_true",
                     help="skip cells already present in --out")
     args = ap.parse_args(argv)
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     results = {}
     if args.resume and os.path.exists(args.out):

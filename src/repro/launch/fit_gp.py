@@ -159,7 +159,11 @@ def _spawn_hosts(args) -> dict:
     """Parent mode: launch K rank copies of this driver and merge results.
 
     The parent only prepares the store and babysits processes — it never
-    touches jax.distributed, so heavy imports are safe here."""
+    touches jax.distributed, so heavy imports are safe here. On an
+    accelerator host it refuses instead (one process per chip)."""
+    from repro.multihost import require_cpu_ranks
+
+    require_cpu_ranks()
     if args.write_store:
         write_store(args)
         store_dir = args.write_store
@@ -296,6 +300,9 @@ def main(argv=None):
 
     ctx = MultihostContext.from_env()
     args = build_parser().parse_args(argv)
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     if args.outputs > 1 and (args.store or args.write_store
                              or args.distributed_hosts):
         raise SystemExit("--outputs > 1 runs the in-core multi-output fit; "

@@ -12,13 +12,11 @@ Axes:
 from __future__ import annotations
 
 import jax
-from jax.sharding import Mesh
-
-from repro.sharding.compat import make_mesh
+from jax.sharding import AxisType, Mesh
 
 
 def _mesh(shape, axes) -> Mesh:
-    return make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:

@@ -153,8 +153,9 @@ def _spawn_serve_hosts(args) -> dict:
     import subprocess
     import sys
 
-    from repro.multihost import ENV_COORD, ENV_NPROCS, ENV_RANK
+    from repro.multihost import ENV_COORD, ENV_NPROCS, ENV_RANK, require_cpu_ranks
 
+    require_cpu_ranks()
     if args.dataset != "synthetic" or args.train_store:
         raise SystemExit("--distributed-hosts serves the in-core synthetic "
                          "dataset (ranks regenerate it deterministically)")
@@ -339,6 +340,9 @@ def serve_gp(argv=None):
 
     ctx = MultihostContext.from_env()
     args = _gp_parser().parse_args(argv)
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     if args.tuning_record:
         from repro.tuning import as_record
 
@@ -566,10 +570,10 @@ def main(argv=None):
     import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
 
+    from repro.compile_cache import enable_compile_cache
     from repro.configs import get_config
     from repro.launch.train import make_mesh
     from repro.models.model import init_params, prefill_step, serve_step
-    from repro.sharding.compat import set_mesh
     from repro.sharding.rules import cache_specs, param_specs, tp_size
 
     ap = argparse.ArgumentParser()
@@ -580,6 +584,7 @@ def main(argv=None):
     ap.add_argument("--max-new", type=int, default=32)
     ap.add_argument("--mesh", default="1x1")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:
@@ -599,7 +604,7 @@ def main(argv=None):
         rng.integers(0, cfg.vocab, size=(args.batch, args.prompt_len)), jnp.int32
     )
 
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         t0 = time.time()
         logits, cache = jax.jit(
             lambda p, t: prefill_step(p, t, cfg, cache_len, tp=tp)

@@ -26,7 +26,6 @@ from repro.data.tokens import TokenStream
 from repro.models.model import init_params
 from repro.sharding.rules import batch_spec, param_specs, tp_size
 from repro.training.train_step import TrainState, make_train_step, train_state_init
-from repro.sharding.compat import set_mesh
 
 
 def make_mesh(spec: str):
@@ -64,6 +63,9 @@ def main(argv=None):
     ap.add_argument("--ckpt-every", type=int, default=50)
     ap.add_argument("--resume", action="store_true")
     args = ap.parse_args(argv)
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     over = {}
@@ -112,7 +114,7 @@ def main(argv=None):
             lambda: (snap["step"], snap["state"], {"stream": stream.state_dict()})
         )
 
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         t_last = time.time()
         for i in range(start_step, start_step + args.steps):
             tok, lab = stream.next()

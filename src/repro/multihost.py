@@ -41,6 +41,26 @@ ENV_COORD = "REPRO_DIST_COORD"
 _KV_PART_BYTES = 2 << 20  # KV values are chunked to stay rendezvous-friendly
 
 
+def require_cpu_ranks() -> None:
+    """Refuse to launch rank processes on an accelerator host.
+
+    The multi-process mode runs one JAX process per rank with CPU (gloo)
+    collectives. An accelerator chip belongs to one process at a time:
+    the launching process holds it once JAX is initialized, and a rank
+    that then asks for it fails or hangs. One process drives all of a
+    host's chips through an in-process mesh instead."""
+    import jax
+
+    platform = jax.default_backend()
+    if platform != "cpu":
+        raise SystemExit(
+            "--distributed-hosts runs one JAX process per rank on CPU "
+            "collectives, but "
+            f"this host's {platform} chips belong to one process. Use "
+            "--workers K: one process drives all local chips through an "
+            "in-process mesh.")
+
+
 def _flat(key: str) -> str:
     """Keep KV keys slash-free: the coordination service treats ``/`` as
     a directory separator (``key_value_dir_get``), so flat keys avoid any
@@ -126,6 +146,12 @@ class MultihostContext:
         from jax.sharding import Mesh
 
         devices = np.asarray(jax.devices())
+        if devices.flat[0].platform != "cpu":
+            raise RuntimeError(
+                "MultihostContext runs CPU (gloo) collectives with one CPU "
+                f"device per process; this process sees {devices.size} "
+                f"{devices.flat[0].platform} devices. Drive accelerator "
+                "chips from one process (--workers K).")
         if devices.size != int(num_processes):
             raise RuntimeError(
                 f"expected one device per process, got {devices.size} devices "
@@ -148,7 +174,6 @@ class MultihostContext:
     def _allreduce_fn(self, length: int, op: str):
         import jax
         import jax.numpy as jnp
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
         def local(x):  # x: this host's (1, length) shard
@@ -157,8 +182,8 @@ class MultihostContext:
                 return jax.lax.psum(v, "hosts")
             return jax.lax.pmax(v, "hosts")
 
-        return jax.jit(shard_map(local, mesh=self._mesh,
-                                 in_specs=(P("hosts"),), out_specs=P()))
+        return jax.jit(jax.shard_map(local, mesh=self._mesh,
+                                     in_specs=(P("hosts"),), out_specs=P()))
 
     def allreduce(self, vec, op: str = "sum") -> np.ndarray:
         """Element-wise sum/max/min across hosts of a float64 vector.

@@ -32,7 +32,8 @@ import numpy as np
 
 from repro.core.kernels_math import KernelParams
 from repro.core.predict import (
-    TrainIndex, iter_query_chunks, pack_queries, packed_predict, scatter_packed,
+    TrainIndex, iter_query_chunks, pack_queries, packed_predict,
+    resolve_backend, scatter_packed,
 )
 from repro.prefetch import Prefetcher
 
@@ -159,19 +160,26 @@ def make_chunk_split(cfg: PipelineConfig):
 
 
 def make_chunk_compute(params: KernelParams, cfg: PipelineConfig, mesh=None,
-                       axis: str = "workers"):
+                       axis: str = "workers", stats: ServerStats | None = None):
     """Return ``compute(pieces) -> [(packed_piece, mu, var), ...]`` over
     the (already split) pieces of one chunk; every piece is dispatched
     asynchronously through the jitted predict program. With a mesh, each
     piece's blocks are sharded by owner first (which reorders them —
     hence every piece is returned alongside its outputs so the scatter
-    uses matching indices)."""
+    uses matching indices). ``cfg.backend`` is resolved per piece before
+    dispatch, and ``stats`` records the concrete backend that ran."""
+    def backend_of(piece):
+        backend = resolve_backend(cfg.backend, params, piece.q_x, piece.nn_x)
+        if stats is not None:
+            stats.record_backend(backend)
+        return backend
+
     if mesh is None:
         def compute(pieces):
             out = []
             for piece in pieces:
                 mu, var = packed_predict(params, piece, nu=cfg.nu,
-                                         backend=cfg.backend)
+                                         backend=backend_of(piece))
                 out.append((piece, mu, var))
             return out
         return compute
@@ -181,7 +189,7 @@ def make_chunk_compute(params: KernelParams, cfg: PipelineConfig, mesh=None,
     def compute(pieces):
         return [
             sharded_packed_predict(params, piece, mesh, axis=axis,
-                                   nu=cfg.nu, backend=cfg.backend)
+                                   nu=cfg.nu, backend=backend_of(piece))
             for piece in pieces
         ]
 
@@ -269,7 +277,7 @@ def run_chunk_stream(
     function, so the drain-mode and continuous-mode paths run one engine
     and cannot drift."""
     split = make_chunk_split(cfg)
-    compute = make_chunk_compute(params, cfg, mesh)
+    compute = make_chunk_compute(params, cfg, mesh, stats=stats)
 
     inflight = None  # (tag, [(piece, mu_dev, var_dev), ...]) — not yet forced
 
@@ -314,7 +322,7 @@ def predict_synchronous(
     n_test = _n_rows(x_test)
     mean, var = _result_zeros(n_test, n_outputs_of(params))
     split = make_chunk_split(cfg)
-    compute = make_chunk_compute(params, cfg, mesh)
+    compute = make_chunk_compute(params, cfg, mesh, stats=stats)
     for _, packed in _chunks(index, x_test, cfg, seed):
         pieces = compute(split(packed))
         _record_pieces(stats, pieces)

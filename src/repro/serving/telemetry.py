@@ -62,6 +62,7 @@ class ServerStats:
         self.latencies_s: deque[float] = deque(maxlen=window)
         self.queue_waits_s: deque[float] = deque(maxlen=window)
         self.compiled_shapes: set[tuple] = set()  # (bc, bs, m, tier) seen by jit
+        self.backends: set[str] = set()  # concrete predict programs dispatched
         self.true_flops = 0.0    # padding-occupancy accounting: useful work
         self.padded_flops = 0.0  # ... vs what the padded shapes execute
         # Continuous-scheduler signals (scheduler.py): per-SLO-class
@@ -94,6 +95,11 @@ class ServerStats:
             self.n_chunks += 1 if count_chunk else 0
             self.compiled_shapes.add((bc, bs, m, tier))
 
+    def record_backend(self, backend: str) -> None:
+        """One piece dispatched to the concrete ``backend`` program."""
+        with self._lock:
+            self.backends.add(backend)
+
     def compiled_shape_keys(self) -> set[tuple]:
         """Snapshot of the ``(bc, bs, m, tier)`` keys seen so far (a copy;
         safe to iterate while the server keeps recording)."""
@@ -119,6 +125,7 @@ class ServerStats:
             self.queue_waits_s.clear()
             if not preserve_shapes:
                 self.compiled_shapes.clear()
+            self.backends.clear()
             self.true_flops = 0.0
             self.padded_flops = 0.0
             self.class_latencies = {}
@@ -195,6 +202,7 @@ class ServerStats:
                 "latency_p99_s": _percentile(lat, 0.99),
                 "queue_wait_p50_s": _percentile(waits, 0.50),
                 "n_compiled_shapes": len(self.compiled_shapes),
+                "backends": sorted(self.backends),
                 "padding_occupancy": (
                     self.true_flops / self.padded_flops
                     if self.padded_flops else 1.0

@@ -16,7 +16,7 @@ import os
 import jax
 from jax.sharding import PartitionSpec as P
 
-from .compat import get_abstract_mesh
+from jax.sharding import get_abstract_mesh
 
 # A/B kill switch for §Perf: REPRO_NO_CONSTRAINTS=1 disables every
 # activation constraint so the un-annotated model can be re-measured

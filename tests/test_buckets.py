@@ -271,6 +271,26 @@ def test_select_backend_policy():
     assert select_backend(4, 8, "loglik", jnp.bfloat16) == "ref"
 
 
+def test_select_backend_keeps_f64_off_compiled_kernels(monkeypatch):
+    """On an accelerator the compiled kernels take f32/bf16 only, so f64
+    (and an unknown dtype) resolves to ref at any shape; f32/bf16 keep
+    the CPU routing."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.kernels.ops import select_backend
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    for kind in ("predict", "loglik"):
+        assert select_backend(100, 400, kind, np.float64) == "ref"
+        assert select_backend(16, 128, kind, np.float64) == "ref"
+        assert select_backend(100, 400, kind) == "ref"
+    assert select_backend(100, 400, "loglik", np.float32) == "pallas"
+    assert select_backend(25, 120, "predict", np.float32) == "pallas"
+    assert select_backend(8, 128, "predict", np.float32) == "pallas_tiled"
+    assert select_backend(16, 128, "predict", jnp.bfloat16) == "pallas_tiled"
+
+
 def test_packed_loglik_pallas_backend_per_bucket(skewed_packed):
     """Bucketed execution with the fused kernel matches ref per bucket."""
     _, _, packed, _ = skewed_packed

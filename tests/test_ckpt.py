@@ -73,9 +73,9 @@ def test_elastic_restore_new_sharding(tmp_path):
 
     state = make_state()
     path = save_checkpoint(str(tmp_path), 3, state)
-    from repro.sharding.compat import make_mesh
+    from repro.launch.mesh import make_worker_mesh
 
-    mesh = make_mesh((1,), ("workers",))
+    mesh = make_worker_mesh(1)
     sh = jax.tree.map(lambda _: NamedSharding(mesh, P()), state)
     restored, _ = restore_train_state(path, state, sh)
     assert_tree_equal(state, restored)

@@ -52,7 +52,6 @@ _MESH_SCRIPT = textwrap.dedent(
     from repro.launch.specs import build_cell
     from repro.sharding.rules import batch_spec, param_specs
     from repro.models.model import init_params
-    from repro.sharding.compat import set_mesh
     from repro.training.train_step import make_train_step, train_state_init
 
     mesh = make_test_mesh((2, 2))
@@ -84,7 +83,7 @@ _MESH_SCRIPT = textwrap.dedent(
     bsh = NamedSharding(mesh, batch_spec(mesh, 4))
     tok = jnp.zeros((4, 64), jnp.int32)
     step = make_train_step(cfg, tp=2, lr=1e-3)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         state2, metrics = jax.jit(
             step, in_shardings=(ssh, bsh, bsh), donate_argnums=(0,)
         )(state, tok, tok)

@@ -239,3 +239,19 @@ def test_two_host_fit_matches_serial(mh_store, serial_nll, tmp_path):
 def test_four_host_fit_matches_serial(mh_store, serial_nll, tmp_path):
     merged = _run_distributed(mh_store, tmp_path, hosts=4)
     _check_parity_and_memory(merged, serial_nll, hosts=4)
+
+
+# -- one process per chip ---------------------------------------------------
+
+
+def test_rank_launch_refused_on_accelerator_host(monkeypatch):
+    """Rank processes would contend for a chip the launcher already holds:
+    on a non-CPU backend the launcher refuses and points to --workers."""
+    import jax
+
+    from repro.multihost import require_cpu_ranks
+
+    require_cpu_ranks()  # the CPU backend launches ranks as before
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(SystemExit, match="--workers"):
+        require_cpu_ranks()

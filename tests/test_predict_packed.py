@@ -104,3 +104,20 @@ def test_backend_and_chunking_consistent_with_loop_free_path():
     np.testing.assert_allclose(b.mean, a.mean, atol=1e-5, rtol=1e-5)
     np.testing.assert_allclose(b.var, a.var, atol=1e-5, rtol=1e-5)
     np.testing.assert_allclose(b.ci_low, a.ci_low, atol=1e-4, rtol=1e-4)
+
+
+@pytest.mark.parametrize("backend", ["ref", "pallas", "auto"])
+def test_predict_stats_record_what_ran(backend):
+    """``Prediction.stats`` names the concrete program each piece ran
+    (``auto`` resolved by select_backend) and times every chunk."""
+    from repro.kernels.ops import select_backend
+
+    params, x, y, xt = _setup(seed=6)
+    pred = predict_sbv(params, x, y, xt, bs_pred=8, m_pred=32, seed=6,
+                       n_sims=4, backend=backend, chunk_size=16)
+    st = pred.stats
+    assert len(st["device_s"]) == 3 and st["host_s"] > 0
+    assert all(bs % 8 == 0 and m == 32 for _, bs, m in st["shapes"])
+    want = {select_backend(8, 32, kind="predict", dtype=np.float64)
+            if backend == "auto" else backend}
+    assert set(st["backends"]) == want

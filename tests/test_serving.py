@@ -125,6 +125,7 @@ def test_max_points_policy_splits_batches_and_stays_exact():
         server.flush()
         results = [f.result(timeout=300) for f in futs]
     assert server.stats.summary()["n_batches"] >= 2
+    assert server.stats.summary()["backends"] == ["ref"]
     for req, res in zip(requests, results):
         em, ev = exact_predict(params, x, y, req)
         np.testing.assert_allclose(res.mean, np.asarray(em), atol=1e-4, rtol=0)

@@ -382,6 +382,46 @@ def test_distributed_streaming_fit_matches_serial():
     assert "STREAM_DIST_OK" in r.stdout
 
 
+STEP_COMPILE_SCRIPT = textwrap.dedent(
+    """
+    import os
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    from repro.core.fit import _chunk_grad_fn, fit_sbv
+    from repro.core.pipeline import SBVConfig
+    from repro.data.gp_sim import paper_synthetic
+    from repro.launch.mesh import make_worker_mesh
+
+    x, y, _ = paper_synthetic(seed=0, n=600, d=4)
+    mesh = make_worker_mesh(4)
+    res = fit_sbv(x, y, SBVConfig(n_blocks=24, m=16, n_workers=4, seed=0),
+                  inner_steps=3, outer_rounds=1, stream_chunk=200,
+                  precision="f32", distributed=(mesh, "workers"))
+    (backend,) = res.stream_stats["backends"]
+    assert res.stream_stats["n_pieces"] > 1
+    step = _chunk_grad_fn(3.5, backend, 600, mesh, "workers")
+    assert step._cache_size() == 1, step._cache_size()
+    print("STEP_COMPILE_OK")
+    """
+)
+
+
+def test_distributed_streaming_step_compiles_once():
+    """The sharded chunk step compiles once for a fit's piece shape. Its
+    gradient comes back replicated over the mesh, so params placed
+    elsewhere would change placement after the first update and compile
+    the step again (4 virtual devices, in a subprocess)."""
+    env = dict(os.environ)
+    env["PYTHONPATH"] = "src"
+    r = subprocess.run(
+        [sys.executable, "-c", STEP_COMPILE_SCRIPT], capture_output=True,
+        text=True,
+        cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        env=env, timeout=300,
+    )
+    assert r.returncode == 0, r.stdout + "\n" + r.stderr
+    assert "STEP_COMPILE_OK" in r.stdout
+
+
 # -- predict parity --------------------------------------------------------
 
 
@@ -539,6 +579,26 @@ def test_lazy_flat_blocks_duplicate_ids_accounted_once(tmp_path, small):
     assert flat.gathered_rows == flat.sizes[3] + flat.sizes[5]
 
 
+def test_lazy_flat_blocks_call_larger_than_cache(tmp_path, small):
+    """One call whose blocks outgrow the byte cap still returns every
+    block. Pre-fix, eviction ran before the result was assembled and
+    dropped blocks of the same call (KeyError) — hit by the filtered NNS
+    at 10k blocks, where one candidate set exceeds the default 32 MB."""
+    from repro.data.streaming import LazyFlatBlocks, streaming_kmeans_blocks
+
+    x, y, _ = small
+    st = ArrayStore.from_arrays(str(tmp_path / "lz"), x, y, shard_rows=400)
+    beta = np.full(4, 0.5)
+    blocks, radii, _ = streaming_kmeans_blocks(st, beta, 12, seed=0)
+    ids = np.arange(12)
+    cap = 8 * 4 * 10  # ten rows: smaller than any one block
+    flat = LazyFlatBlocks(blocks, radii, st, beta, cache_bytes=cap)
+    out = flat.points_of_blocks(ids)
+    want = LazyFlatBlocks(blocks, radii, st, beta).points_of_blocks(ids)
+    np.testing.assert_array_equal(out, want)
+    assert len(flat._cache) == 1  # evicted back down once the call returned
+
+
 def _tiny_packed():
     from repro.core.packing import PackedBlocks
 
@@ -665,3 +725,28 @@ def test_working_set_model_terms(small):
     ws = working_set_model(res.stream_stats, len(y), 4, cfg.m, 300)
     assert all(v > 0 for v in ws["terms"].values())
     assert ws["total"] == sum(ws["terms"].values())
+
+
+def test_device_cache_budget_needs_accelerator_memory_stats(monkeypatch):
+    """On an accelerator the budget comes from the device's own memory
+    stats; host RAM is never a stand-in for HBM."""
+    import jax
+
+    from repro.data.streaming import device_cache_budget
+
+    class _Dev:
+        platform = "tpu"
+
+        def __init__(self, stats):
+            self._stats = stats
+
+        def memory_stats(self):
+            return self._stats
+
+    gib = 1 << 30
+    monkeypatch.setattr(jax, "devices", lambda *a: [_Dev(
+        {"bytes_limit": 16 * gib, "bytes_in_use": 2 * gib})])
+    assert device_cache_budget(frac=0.5, reserve_bytes=gib) == 6 * gib
+    monkeypatch.setattr(jax, "devices", lambda *a: [_Dev(None)])
+    with pytest.raises(RuntimeError, match="memory_stats"):
+        device_cache_budget()

@@ -116,6 +116,16 @@ def test_compiled_shape_keys_returns_a_snapshot():
     assert len(stats.compiled_shape_keys()) == 2
 
 
+def test_backends_recorded_and_cleared_on_reset():
+    stats = ServerStats()
+    stats.record_backend("pallas")
+    stats.record_backend("ref")
+    stats.record_backend("pallas")
+    assert stats.summary()["backends"] == ["pallas", "ref"]
+    stats.reset()
+    assert stats.summary()["backends"] == []
+
+
 # -- reset semantics -------------------------------------------------------
 
 
