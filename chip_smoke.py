@@ -313,11 +313,11 @@ def phase_predict(params, x, y, x_test, y_test, seed, device, clock):
     mspe = float(np.mean((pred.mean - y_test) ** 2))
     cover = float(np.mean((y_test >= pred.ci_low) & (y_test <= pred.ci_high)))
     rec = dict(phase="predict", n_test=len(x_test), n_sims=N_SIMS,
-               chunks=len(st["device_s"]), chunk_shapes=st["shapes"],
+               chunks=len(st["fetch_s"]), chunk_shapes=st["shapes"],
                backends=st["backends"],
                pallas_in_hlo=simulate_programs(params, pred, N_SIMS),
                wall_s=wall_s, host_preprocess_s=st["host_s"],
-               compile_s=compile_s, chunk_device_s=st["device_s"],
+               compile_s=compile_s, chunk_fetch_s=st["fetch_s"],
                run_s=wall_s - st["host_s"] - compile_s,
                points_per_s=len(x_test) / wall_s,
                mspe=mspe, ci95_coverage=cover, var_y_test=float(np.var(y_test)),

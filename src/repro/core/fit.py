@@ -22,6 +22,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.optim import adam_init, adam_update
+from repro.spans import span
 
 from .kernels_math import KernelParams
 from .pipeline import SBVConfig, preprocess
@@ -493,89 +494,95 @@ def _fit_sbv_streaming(
              "h2d_bytes_per_step": 0, "inner_steps_total": 0,
              "inner_time_s": 0.0, "precision": tier or "f64",
              "device_cache_budget": 0, "struct_time_s": 0.0,
+             "struct_kmeans_s": 0.0, "struct_nns_s": 0.0,
+             "struct_pack_s": 0.0, "nns_scored": 0, "nns_kept": 0,
              "step_times_s": [], "backends": []}
 
     for outer in range(outer_rounds):
-        t_struct = time.perf_counter()
-        beta_np = np.asarray(params.beta)
-        struct = streaming_preprocess(store, beta_np, cfg, stream_chunk)
-        bc_pad = max(len(r) for r in struct.plan)
-        if n_shards > 1:
-            # every piece's block count must divide the shard count; pad
-            # the SHARED shape so all pieces still hit one compiled program
-            bc_pad = round_up(bc_pad, n_shards)
-
-        if n_buckets:
-            # GLOBAL bucket ceilings + per-cell bc padding: every chunk's
-            # pieces land on one of <= occupied-cells shapes, so the
-            # round compiles a bounded program set (per-chunk ceilings
-            # would compile — and grow the XLA arena — per chunk).
-            from .buckets import _group, bucket_ceilings
-
-            bs_true = np.asarray(
-                [struct.blocks.members[b].size for b in struct.blocks.order])
-            m_true = np.asarray(
-                [min(len(struct.neigh[b]), cfg.m) for b in struct.blocks.order])
-            bs_ceils = bucket_ceilings(bs_true, n_buckets, 8)
-            m_ceils = bucket_ceilings(m_true, n_buckets, 8)
-            cell_bc: dict = {}
-            for ranks in struct.plan:
-                for bs_c, m_c, idx in _group(bs_true[ranks], m_true[ranks],
-                                             bs_ceils, m_ceils):
-                    # Same clamp bucket_blocks applies to piece shapes.
-                    key = (min(bs_c, struct.bs_max), min(m_c, cfg.m))
-                    cell_bc[key] = max(cell_bc.get(key, 0), round_up(idx.size, 8))
-            if n_shards > 1:
-                cell_bc = {k: round_up(v, n_shards) for k, v in cell_bc.items()}
-
-        if device_cache is None:
-            # Auto budget: free device memory minus the grad live-set
-            # reserve (the working_set_model device_grad term). The
-            # reserve is PRECISION-AWARE: reduced tiers accumulate in
-            # f32, so the backward live set is half the f64 bytes — the
-            # freed reserve goes straight to the device-resident cache.
-            acc_bytes = 4 if tier else int(np.dtype(cfg.dtype).itemsize)
-            reserve = 16 * _MAP_BATCH * (struct.bs_max + cfg.m) ** 2 * acc_bytes
-            budget = device_cache_budget(reserve_bytes=reserve)
-        else:
-            budget = int(device_cache)
-        stats["device_cache_budget"] = max(stats["device_cache_budget"], budget)
-        work_dir = spool_dir or tempfile.mkdtemp(prefix="sbv-spool-")
-        spool = PackedChunkSpool(os.path.join(work_dir, f"round{outer}"),
-                                 device_budget=budget, sharding=sharding)
-        backends = set()
+        spool = work_dir = None
         try:
-            for ranks in struct.plan:
-                packed = pack_block_chunk(
-                    store, struct.blocks, struct.neigh, ranks,
-                    m=cfg.m, bs_max=struct.bs_max, dtype=cfg.dtype,
-                )
+            # struct_time_s: the round's structure, packing and spooling
+            with span("sbv.fit.structure", stats, "struct_time_s"):
+                beta_np = np.asarray(params.beta)
+                struct = streaming_preprocess(store, beta_np, cfg, stream_chunk)
+                for key, v in struct.stats.items():
+                    stats[key] += v
+                bc_pad = max(len(r) for r in struct.plan)
+                if n_shards > 1:
+                    # every piece's block count must divide the shard count; pad
+                    # the SHARED shape so all pieces still hit one compiled program
+                    bc_pad = round_up(bc_pad, n_shards)
+
                 if n_buckets:
-                    from .buckets import bucket_blocks
+                    # GLOBAL bucket ceilings + per-cell bc padding: every chunk's
+                    # pieces land on one of <= occupied-cells shapes, so the
+                    # round compiles a bounded program set (per-chunk ceilings
+                    # would compile — and grow the XLA arena — per chunk).
+                    from .buckets import _group, bucket_ceilings
 
-                    bucketed = bucket_blocks(packed, ceilings=(bs_ceils, m_ceils))
-                    groups = _group(bs_true[ranks], m_true[ranks],
-                                    bs_ceils, m_ceils)
-                    pieces = [
-                        p.pad_to_blocks(cell_bc[(min(bs_c, packed.bs_max),
-                                                 min(m_c, packed.m))])
-                        for (bs_c, m_c, _), p in zip(groups, bucketed.buckets)
-                    ]
-                else:
-                    pieces = [packed.pad_to_blocks(bc_pad)]
-                for p in pieces:
-                    if tier:
-                        from .buckets import cast_packed
-
-                        p = cast_packed(p, tier)
+                    bs_true = np.asarray(
+                        [struct.blocks.members[b].size for b in struct.blocks.order])
+                    m_true = np.asarray(
+                        [min(len(struct.neigh[b]), cfg.m) for b in struct.blocks.order])
+                    bs_ceils = bucket_ceilings(bs_true, n_buckets, 8)
+                    m_ceils = bucket_ceilings(m_true, n_buckets, 8)
+                    cell_bc: dict = {}
+                    for ranks in struct.plan:
+                        for bs_c, m_c, idx in _group(bs_true[ranks], m_true[ranks],
+                                                     bs_ceils, m_ceils):
+                            # Same clamp bucket_blocks applies to piece shapes.
+                            key = (min(bs_c, struct.bs_max), min(m_c, cfg.m))
+                            cell_bc[key] = max(cell_bc.get(key, 0), round_up(idx.size, 8))
                     if n_shards > 1:
-                        # owner-contiguous reorder; bc already divides the
-                        # shard count, so the shape is unchanged
-                        p = shard_blocks_by_owner(p, n_shards)
-                    piece_backend = _piece_backend(backend, p)
-                    backends.add(piece_backend)
-                    spool.add(p, tag=piece_backend)
-            stats["struct_time_s"] += time.perf_counter() - t_struct
+                        cell_bc = {k: round_up(v, n_shards) for k, v in cell_bc.items()}
+
+                if device_cache is None:
+                    # Auto budget: free device memory minus the grad live-set
+                    # reserve (the working_set_model device_grad term). The
+                    # reserve is PRECISION-AWARE: reduced tiers accumulate in
+                    # f32, so the backward live set is half the f64 bytes — the
+                    # freed reserve goes straight to the device-resident cache.
+                    acc_bytes = 4 if tier else int(np.dtype(cfg.dtype).itemsize)
+                    reserve = 16 * _MAP_BATCH * (struct.bs_max + cfg.m) ** 2 * acc_bytes
+                    budget = device_cache_budget(reserve_bytes=reserve)
+                else:
+                    budget = int(device_cache)
+                stats["device_cache_budget"] = max(stats["device_cache_budget"], budget)
+                work_dir = spool_dir or tempfile.mkdtemp(prefix="sbv-spool-")
+                spool = PackedChunkSpool(os.path.join(work_dir, f"round{outer}"),
+                                         device_budget=budget, sharding=sharding)
+                backends = set()
+                with span("sbv.fit.struct.pack", stats, "struct_pack_s"):
+                    for ranks in struct.plan:
+                        packed = pack_block_chunk(
+                            store, struct.blocks, struct.neigh, ranks,
+                            m=cfg.m, bs_max=struct.bs_max, dtype=cfg.dtype,
+                        )
+                        if n_buckets:
+                            from .buckets import bucket_blocks
+
+                            bucketed = bucket_blocks(packed, ceilings=(bs_ceils, m_ceils))
+                            groups = _group(bs_true[ranks], m_true[ranks],
+                                            bs_ceils, m_ceils)
+                            pieces = [
+                                p.pad_to_blocks(cell_bc[(min(bs_c, packed.bs_max),
+                                                         min(m_c, packed.m))])
+                                for (bs_c, m_c, _), p in zip(groups, bucketed.buckets)
+                            ]
+                        else:
+                            pieces = [packed.pad_to_blocks(bc_pad)]
+                        for p in pieces:
+                            if tier:
+                                from .buckets import cast_packed
+
+                                p = cast_packed(p, tier)
+                            if n_shards > 1:
+                                # owner-contiguous reorder; bc already divides the
+                                # shard count, so the shape is unchanged
+                                p = shard_blocks_by_owner(p, n_shards)
+                            piece_backend = _piece_backend(backend, p)
+                            backends.add(piece_backend)
+                            spool.add(p, tag=piece_backend)
             stats["backends"] = sorted(backends)
             stats.update(
                 n_chunks=len(struct.plan), n_pieces=len(spool),
@@ -600,28 +607,34 @@ def _fit_sbv_streaming(
                 # step would compile a second time.
                 params = jax.device_put(params, NamedSharding(mesh, P()))
             state = adam_init(params)
-            t_inner = time.perf_counter()
-            for it in range(inner_steps):
-                t_step = time.perf_counter()
-                loss = None
-                grad = None
-                for arrs, piece_backend in spool.iter_arrays(prefetch=prefetch):
-                    grad_fn = _chunk_grad_fn(nu, piece_backend, n, mesh, axis)
-                    v, g = grad_fn(params, *arrs)
-                    loss = v if loss is None else loss + v
-                    grad = g if grad is None else jax.tree.map(jnp.add, grad, g)
-                params, state = adam_update(grad, state, params, lr)
-                history.append((outer, it, float(loss)))  # float() syncs
-                stats["step_times_s"].append(time.perf_counter() - t_step)
-                if verbose and it % 10 == 0:
-                    print(f"[fit-stream] outer={outer} it={it} "
-                          f"nll/n={float(loss):.6f} pieces={len(spool)} "
-                          f"(device-cached {spool.n_device})")
-            stats["inner_time_s"] += time.perf_counter() - t_inner
+            with span("sbv.fit.steps", stats, "inner_time_s"):
+                for it in range(inner_steps):
+                    step = stats["inner_steps_total"] + it
+                    # step_times_s: first piece to the loss on the host
+                    with span("sbv.fit.step", stats, "step_times_s",
+                              step=step):
+                        loss = None
+                        grad = None
+                        for piece, (arrs, piece_backend) in enumerate(
+                                spool.iter_arrays(prefetch=prefetch)):
+                            with span("sbv.fit.piece", step=step, piece=piece):
+                                grad_fn = _chunk_grad_fn(nu, piece_backend, n, mesh, axis)
+                                v, g = grad_fn(params, *arrs)
+                                loss = v if loss is None else loss + v
+                                grad = g if grad is None else jax.tree.map(jnp.add, grad, g)
+                        with span("sbv.fit.adam_update", step=step):
+                            params, state = adam_update(grad, state, params, lr)
+                        with span("sbv.fit.sync", step=step):
+                            history.append((outer, it, float(loss)))  # float() syncs
+                    if verbose and it % 10 == 0:
+                        print(f"[fit-stream] outer={outer} it={it} "
+                              f"nll/n={float(loss):.6f} pieces={len(spool)} "
+                              f"(device-cached {spool.n_device})")
             stats["inner_steps_total"] += inner_steps
         finally:
-            spool.cleanup()
-            if spool_dir is None:
+            if spool is not None:
+                spool.cleanup()
+            if spool_dir is None and work_dir is not None:
                 shutil.rmtree(work_dir, ignore_errors=True)
     return FitResult(params=params, history=history, packed=None,
                      stream_stats=stats)

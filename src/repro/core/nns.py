@@ -106,6 +106,7 @@ def filtered_nns(
     center_chunk: int = 2048,
     flat: _FlatBlocks | None = None,
     domain_volume: float | None = None,
+    stats: dict | None = None,
 ) -> list[np.ndarray]:
     """Exact preceding-block m-NNS per block via filtered candidate sets.
 
@@ -116,6 +117,11 @@ def filtered_nns(
     Streaming callers pass ``x_scaled=None`` with a store-backed ``flat``
     plus a precomputed ``domain_volume`` (chunk-accumulated min/max extent
     gives the same floats as the in-core formula).
+
+    ``stats`` gets two counters added: ``nns_scored``, the candidate points
+    whose distance was computed (every pass of the doubling fallback
+    counts), and ``nns_kept``, the neighbours returned; their ratio is the
+    filter's work per useful neighbour.
     """
     if flat is None:
         flat = _FlatBlocks(x_scaled, blocks)
@@ -129,6 +135,7 @@ def filtered_nns(
     ranks = blocks.rank_of_block
     c2 = np.sum(centers * centers, axis=1)
     neigh: list[np.ndarray] = [np.empty(0, np.int64)] * bc
+    scored = 0
 
     for s in range(0, bc, center_chunk):
         e = min(bc, s + center_chunk)
@@ -137,7 +144,13 @@ def filtered_nns(
         np.sqrt(np.maximum(dc, 0.0, out=dc), out=dc)
         for bi in range(s, e):
             if ranks[bi] > 0:
-                neigh[bi] = _one_block(bi, centers[bi], dc[bi - s], lam, m, ranks, flat)
+                neigh[bi], n_scored = _one_block(bi, centers[bi], dc[bi - s],
+                                                 lam, m, ranks, flat)
+                scored += n_scored
+    if stats is not None:
+        stats["nns_scored"] = stats.get("nns_scored", 0) + scored
+        stats["nns_kept"] = (stats.get("nns_kept", 0)
+                             + sum(int(nb.size) for nb in neigh))
     return neigh
 
 
@@ -151,10 +164,12 @@ def _topm(rows: np.ndarray, d2p: np.ndarray, m: int, flat: _FlatBlocks) -> np.nd
     return flat.flat_idx[rows[part]].astype(np.int64)
 
 
-def _one_block(bi, center, dist_c, lam, m, ranks, flat) -> np.ndarray:
+def _one_block(bi, center, dist_c, lam, m, ranks, flat):
+    """``(neighbours, points scored)`` of block ``bi``."""
     my_rank = ranks[bi]
     n_prec = int(my_rank)  # number of preceding blocks
     lam_try = lam
+    scored = 0
     for _ in range(40):
         keep = (dist_c <= lam_try + flat.radii) & (ranks < my_rank)
         cand_blocks = np.nonzero(keep)[0]
@@ -162,15 +177,16 @@ def _one_block(bi, center, dist_c, lam, m, ranks, flat) -> np.ndarray:
         if cand_blocks.size:
             rows = flat.rows_of_blocks(cand_blocks)
             d2p = np.sum((flat.points_of_blocks(cand_blocks) - center) ** 2, axis=1)
+            scored += rows.size
             fine = d2p <= lam_try * lam_try
             n_fine = int(fine.sum())
             if n_fine >= m:
-                return _topm(rows[fine], d2p[fine], m, flat)
+                return _topm(rows[fine], d2p[fine], m, flat), scored
             if covered:
                 # Whole preceding set is already candidate: brute is exact.
-                return _topm(rows, d2p, m, flat)
+                return _topm(rows, d2p, m, flat), scored
         elif covered:  # no preceding blocks at all
-            return np.empty(0, dtype=np.int64)
+            return np.empty(0, dtype=np.int64), scored
         lam_try *= 2.0
     raise RuntimeError("filtered NNS failed to converge (degenerate geometry?)")
 

@@ -30,6 +30,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.spans import span
+
 from .blocks import BlockStructure, build_blocks, scale_inputs
 from .kernels_math import KernelParams
 from .nns import _FlatBlocks, filtered_knn_points
@@ -49,10 +51,11 @@ class Prediction:
     ci_low: np.ndarray     # 95% CI bounds from simulation
     ci_high: np.ndarray
     # What the call ran: ``backends`` (the concrete predict programs),
-    # ``shapes`` ((bc, bs_pred, m_pred) per piece), ``host_s`` (training
-    # index + query NNS/packing + casts) and ``device_s`` (per chunk:
-    # dispatch, device run, fetch and scatter; a first compile lands in
-    # the first chunk).
+    # ``shapes`` ((bc, bs_pred, m_pred) per piece), ``host_s`` (everything
+    # but the chunks' dispatch and fetch: training index, query NNS and
+    # packing, casts), within it ``index_s`` (the training index) and
+    # ``query_s`` (query NNS and packing), and ``fetch_s`` (per piece: the
+    # wait for the device, the copy to the host and the scatter).
     stats: dict = field(default_factory=dict)
 
 
@@ -197,6 +200,7 @@ def iter_query_chunks(
     n_workers: int = 1,
     chunk_size: int | None = None,
     dtype=np.float64,
+    stats: dict | None = None,
 ):
     """Yield ``(chunk_id, PackedPrediction)`` over the test set.
 
@@ -205,7 +209,8 @@ def iter_query_chunks(
     offsets, and jit-stable padded shapes in chunked mode all live HERE so
     the two paths cannot drift. ``x_test`` may be a row store, in which
     case each window is read on demand (``chunk_size`` is then required —
-    reading an out-of-core test set whole would defeat the store)."""
+    reading an out-of-core test set whole would defeat the store).
+    ``stats["query_s"]`` gets the seconds of the chunks' packing."""
     from repro.data.store import is_store
 
     if is_store(x_test):
@@ -221,11 +226,13 @@ def iter_query_chunks(
     step = n_test if chunk_size is None else max(int(chunk_size), bs_pred)
     for ci, start in enumerate(range(0, n_test, step)):
         stop = min(n_test, start + step)
-        yield ci, pack_queries(
-            index, window(start, stop), bs_pred, m_pred, alpha=alpha,
-            seed=seed + ci, n_workers=n_workers, offset=start,
-            pad_shapes=chunk_size is not None, dtype=dtype,
-        )
+        with span("sbv.predict.query", stats, "query_s", chunk=ci):
+            packed = pack_queries(
+                index, window(start, stop), bs_pred, m_pred, alpha=alpha,
+                seed=seed + ci, n_workers=n_workers, offset=start,
+                pad_shapes=chunk_size is not None, dtype=dtype,
+            )
+        yield ci, packed
 
 
 def _predict_multi_one(params, nu, qx, qmask, nx, ny, nmask):
@@ -388,6 +395,7 @@ def _slice_prediction_blocks(p: PackedPrediction, lo: int,
     )
 
 
+@span("sbv.predict")
 def predict_sbv(
     params: KernelParams,
     x_train: np.ndarray,
@@ -500,6 +508,8 @@ def predict_sbv(
     elif isinstance(params, MultiOutputParams):
         params = params.output_params(0)
 
+    stats = {"backends": set(), "shapes": set(), "host_s": 0.0,
+             "index_s": 0.0, "query_s": 0.0, "fetch_s": []}
     t_mark = time.perf_counter()
     beta = np.asarray(params.beta if beta_struct is None else beta_struct)
     if is_store(x_test):
@@ -509,8 +519,9 @@ def predict_sbv(
     else:
         x_test = np.asarray(x_test, dtype=np.float64)
         n_test = x_test.shape[0]
-    index = build_train_index(x_train, y_train, beta, m_pred, n_workers, seed,
-                              stream_chunk=stream_chunk)
+    with span("sbv.predict.train_index", stats, "index_s"):
+        index = build_train_index(x_train, y_train, beta, m_pred, n_workers,
+                                  seed, stream_chunk=stream_chunk)
 
     out_shape = (n_test,) if n_outputs == 1 else (n_test, n_outputs)
     mean = np.zeros(out_shape)
@@ -518,12 +529,10 @@ def predict_sbv(
     sim_mean = np.zeros(out_shape)
     sim_std = np.zeros(out_shape)
     key = jax.random.PRNGKey(seed)
-    stats = {"backends": set(), "shapes": set(), "host_s": 0.0,
-             "device_s": []}
 
     for ci, packed in iter_query_chunks(
         index, x_test, bs_pred, m_pred, alpha=alpha, seed=seed,
-        n_workers=n_workers, chunk_size=chunk_size, dtype=dtype,
+        n_workers=n_workers, chunk_size=chunk_size, dtype=dtype, stats=stats,
     ):
         if n_buckets:
             from .buckets import bucket_mults, bucket_prediction
@@ -539,6 +548,8 @@ def predict_sbv(
 
             pieces = [cast_prediction(p, tier) for p in pieces]
         key_c = jax.random.fold_in(key, ci)
+        # host_s is the complement of the chunks' dispatch and fetch, a
+        # stretch that resumes the chunk generator: no span can hold it
         t_dev = time.perf_counter()
         stats["host_s"] += t_dev - t_mark
         for bi, piece in enumerate(pieces):
@@ -554,8 +565,9 @@ def predict_sbv(
                     params, *(jnp.asarray(a) for a in piece.arrays()),
                     key_b, nu=nu, backend=piece_backend, n_sims=n_sims,
                 )
-                scatter_packed(piece, (mu_b, mean), (var_b, var),
-                               (sm_b, sim_mean), (ss_b, sim_std))
+                with span("sbv.predict.fetch", stats, "fetch_s", chunk=ci):
+                    scatter_packed(piece, (mu_b, mean), (var_b, var),
+                                   (sm_b, sim_mean), (ss_b, sim_std))
                 continue
             # Multi-host: this rank computes only its contiguous block
             # span; the full-chunk eps stream is sliced inside the jit so
@@ -571,10 +583,10 @@ def predict_sbv(
                     key_b, nu=nu, backend=piece_backend, n_sims=n_sims,
                     lo=lo, bc_full=bc_full,
                 )
-                scatter_packed(sub, (mu_b, mean), (var_b, var),
-                               (sm_b, sim_mean), (ss_b, sim_std))
+                with span("sbv.predict.fetch", stats, "fetch_s", chunk=ci):
+                    scatter_packed(sub, (mu_b, mean), (var_b, var),
+                                   (sm_b, sim_mean), (ss_b, sim_std))
         t_mark = time.perf_counter()
-        stats["device_s"].append(t_mark - t_dev)
 
     if multihost is not None:
         # Ranks filled disjoint result rows (block spans own disjoint

@@ -36,13 +36,14 @@ from __future__ import annotations
 
 import os
 from collections import OrderedDict
-from dataclasses import dataclass
+from dataclasses import dataclass, field
 
 import numpy as np
 
 from repro.core.blocks import BlockStructure, most_relevant_dim, scale_inputs
 from repro.core.nns import _FlatBlocks, filtered_nns
 from repro.core.packing import PackedBlocks, pack_blocks
+from repro.spans import span
 
 DEFAULT_STRUCT_BATCH = 65536  # rows per structure pass (decoupled from
                               # stream_chunk so the packing window can vary
@@ -342,20 +343,21 @@ class LazyFlatBlocks(_FlatBlocks):
 def streaming_filtered_nns(
     store, blocks: BlockStructure, radii: np.ndarray, beta: np.ndarray,
     m: int, alpha: float = 100.0, domain_volume: float | None = None,
-    cache_bytes: int = 32 << 20,
+    cache_bytes: int = 32 << 20, stats: dict | None = None,
 ):
     """Filtered preceding-block NNS with store-backed candidate gathers.
 
     The query sweep runs in block-id order == center-coordinate order
     (see ``streaming_kmeans_blocks``), so consecutive queries share most
     of their candidate blocks and the LRU cache bounds re-reads.
-    Returns ``(neighbors, flat)`` so callers can keep the warm index.
+    Returns ``(neighbors, flat)`` so callers can keep the warm index;
+    ``stats`` gets the ``filtered_nns`` counters.
     """
     flat = LazyFlatBlocks(blocks, radii, store, beta, cache_bytes=cache_bytes)
     bc = max(blocks.n_blocks, 1)
     center_chunk = max(16, min(2048, MAX_D2_ENTRIES // bc))
     neigh = filtered_nns(None, blocks, m, alpha=alpha, center_chunk=center_chunk,
-                         flat=flat, domain_volume=domain_volume)
+                         flat=flat, domain_volume=domain_volume, stats=stats)
     return neigh, flat
 
 
@@ -670,6 +672,9 @@ class StreamStructure:
     domain_volume: float
     plan: list
     bs_max: int
+    # Seconds of the stages (``struct_kmeans_s``, ``struct_nns_s``) and the
+    # NNS counters (``nns_scored``, ``nns_kept``; see ``filtered_nns``).
+    stats: dict = field(default_factory=dict)
 
 
 def streaming_preprocess(
@@ -683,21 +688,25 @@ def streaming_preprocess(
     of ``cfg.clustering``, and the structure batch size is decoupled from
     ``stream_chunk`` so the packing window can change without changing
     the block structure."""
-    blocks, radii, vol = streaming_kmeans_blocks(
-        store, beta, cfg.n_blocks, n_workers=cfg.n_workers, seed=cfg.seed,
-        batch_rows=struct_batch or DEFAULT_STRUCT_BATCH,
-        ordering=cfg.ordering,
-    )
-    neigh, flat = streaming_filtered_nns(
-        store, blocks, radii, beta, cfg.m, alpha=cfg.alpha,
-        domain_volume=vol, cache_bytes=cache_bytes,
-    )
+    stats: dict = {}
+    with span("sbv.fit.struct.kmeans", stats, "struct_kmeans_s"):
+        blocks, radii, vol = streaming_kmeans_blocks(
+            store, beta, cfg.n_blocks, n_workers=cfg.n_workers, seed=cfg.seed,
+            batch_rows=struct_batch or DEFAULT_STRUCT_BATCH,
+            ordering=cfg.ordering,
+        )
+    with span("sbv.fit.struct.nns", stats, "struct_nns_s"):
+        neigh, flat = streaming_filtered_nns(
+            store, blocks, radii, beta, cfg.m, alpha=cfg.alpha,
+            domain_volume=vol, cache_bytes=cache_bytes, stats=stats,
+        )
     plan = plan_block_chunks(blocks, neigh, cfg.m, stream_chunk)
     bs_max = int(max(mb.size for mb in blocks.members))
     if cfg.bs_max is not None:
         bs_max = max(bs_max, cfg.bs_max)
     return StreamStructure(blocks=blocks, neigh=neigh, flat=flat,
-                           domain_volume=vol, plan=plan, bs_max=bs_max)
+                           domain_volume=vol, plan=plan, bs_max=bs_max,
+                           stats=stats)
 
 
 # -- multi-host construction (Alg. 2 across processes) ---------------------
@@ -1214,8 +1223,8 @@ def multihost_filtered_nns(
         still = []
         for bi in pending:
             try:
-                neigh[bi] = _one_block(bi, centers[bi], dist_cache[bi], lam,
-                                       m, ranks, flat)
+                neigh[bi], _ = _one_block(bi, centers[bi], dist_cache[bi],
+                                          lam, m, ranks, flat)
             except _HaloMiss as e:
                 misses.update(int(b) for b in e.missing)
                 still.append(bi)

@@ -327,9 +327,11 @@ def _block_spec(*tail):
 
 
 def _loglik_call(kernel, beta, sigma2, nugget, blk_x, blk_y, blk_mask,
-                 nn_x, nn_y, nn_mask, y_shape, out_cols, nu, interpret):
+                 nn_x, nn_y, nn_mask, y_shape, out_cols, nu, interpret, name):
     """``pallas_call`` over one grid step per block; shared by the single-
-    and multi-output likelihood kernels."""
+    and multi-output likelihood kernels. ``name`` names the kernel's
+    instruction in compiled programs and device traces, whatever
+    transformation (jvp, shard_map) calls it."""
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     bc, bs, d = blk_x.shape
@@ -355,6 +357,7 @@ def _loglik_call(kernel, beta, sigma2, nugget, blk_x, blk_y, blk_mask,
         out_specs=_block_spec(1, out_cols),
         out_shape=jax.ShapeDtypeStruct((bc, 1, out_cols), dtype),
         interpret=interpret,
+        name=name,
     )(*params, blk_x, blk_y, _rows(blk_mask), nn_x, nn_y, _rows(nn_mask))
     return out[:, 0, :]
 
@@ -373,7 +376,7 @@ def sbv_multi_stats_pallas(
     p = blk_y.shape[2]
     return _loglik_call(_sbv_multi_kernel, beta, sigma2, nugget, blk_x, blk_y,
                         blk_mask, nn_x, nn_y, nn_mask, lambda n: (n, p), 1 + p,
-                        nu, interpret)
+                        nu, interpret, "sbv_multi_stats_pallas")
 
 
 @functools.partial(jax.jit, static_argnames=("nu", "interpret"))
@@ -392,5 +395,5 @@ def sbv_loglik_pallas(
     """
     out = _loglik_call(_sbv_kernel, beta, sigma2, nugget, blk_x, _rows(blk_y),
                        blk_mask, nn_x, _rows(nn_y), nn_mask, lambda n: (1, n), 1,
-                       nu, interpret)
+                       nu, interpret, "sbv_loglik_pallas")
     return out[:, 0]

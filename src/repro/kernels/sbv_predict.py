@@ -96,6 +96,7 @@ def sbv_predict_pallas(
         out_specs=(_block_spec(1, bs), _block_spec(1, bs)),
         out_shape=(row, row),
         interpret=interpret,
+        name="sbv_predict_pallas",
     )(*params, q_x, _rows(q_mask), nn_x, _rows(nn_y), _rows(nn_mask))
     return mu[:, 0, :], var[:, 0, :]
 

@@ -157,3 +157,24 @@ def test_prebuilt_flat_index_gives_identical_results():
     b = filtered_nns(xs, blocks, 10)
     for ga, gb in zip(a, b):
         np.testing.assert_array_equal(ga, gb)
+
+
+@pytest.mark.parametrize("alpha", [1.5, 100.0])
+def test_filtered_nns_counters(alpha):
+    """``nns_kept`` counts the neighbours returned and ``nns_scored`` the
+    candidate points scored, doubling passes included; counting changes
+    no answer."""
+    rng = np.random.default_rng(11)
+    x = rng.uniform(size=(200, 3))
+    beta = np.asarray([0.3, 1.0, 4.0])
+    xs = scale_inputs(x, beta)
+    blocks = build_blocks(xs, 20, 1, beta, seed=11)
+    stats = {}
+    got = filtered_nns(xs, blocks, 10, alpha=alpha, stats=stats)
+    assert stats["nns_kept"] == sum(nb.size for nb in got) > 0
+    assert stats["nns_scored"] >= stats["nns_kept"]
+    plain = filtered_nns(xs, blocks, 10, alpha=alpha)
+    assert all(np.array_equal(a, b) for a, b in zip(got, plain))
+    # a second call adds to the same counters
+    filtered_nns(xs, blocks, 10, alpha=alpha, stats=stats)
+    assert stats["nns_kept"] == 2 * sum(nb.size for nb in got)

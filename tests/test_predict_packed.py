@@ -116,7 +116,7 @@ def test_predict_stats_record_what_ran(backend):
     pred = predict_sbv(params, x, y, xt, bs_pred=8, m_pred=32, seed=6,
                        n_sims=4, backend=backend, chunk_size=16)
     st = pred.stats
-    assert len(st["device_s"]) == 3 and st["host_s"] > 0
+    assert len(st["fetch_s"]) == 3 and st["host_s"] > 0
     assert all(bs % 8 == 0 and m == 32 for _, bs, m in st["shapes"])
     want = {select_backend(8, 32, kind="predict", dtype=np.float64)
             if backend == "auto" else backend}

@@ -192,6 +192,22 @@ def test_streaming_fit_store_equals_incore(tmp_path, small):
     assert r_disk.stream_stats["n_chunks"] > 1
 
 
+def test_stream_stats_split_the_structure_and_time_every_step(small):
+    """The structure's stages lie inside ``struct_time_s``, the NNS
+    counters are filled, and every inner step of every round has one
+    ``step_times_s`` entry."""
+    x, y, _ = small
+    cfg = SBVConfig(n_blocks=24, m=20, seed=0)
+    st = fit_sbv(x, y, cfg, inner_steps=3, outer_rounds=2,
+                 stream_chunk=400).stream_stats
+    parts = [st["struct_kmeans_s"], st["struct_nns_s"], st["struct_pack_s"]]
+    assert all(p > 0 for p in parts)
+    assert sum(parts) <= st["struct_time_s"]
+    assert st["nns_scored"] >= st["nns_kept"] > 0
+    assert len(st["step_times_s"]) == 6
+    assert sum(st["step_times_s"]) <= st["inner_time_s"]
+
+
 def test_chunked_fit_matches_monolithic_1e10(small):
     """Chunked grad accumulation vs the single-chunk program: identical
     structure (struct batch is decoupled from stream_chunk), so only the
